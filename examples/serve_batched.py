@@ -30,7 +30,7 @@ for arch in ("gemma-2b", "rwkv6-1.6b", "whisper-small"):
 
     eng = ServeEngine(api, params, batch=B, s_max=S0 + new + 4)
     t0 = time.perf_counter()
-    out = eng.generate(inputs, max_new_tokens=new)
+    out, _ = eng.generate(inputs, max_new_tokens=new)
     dt = time.perf_counter() - t0
     print(
         f"{arch:14s} generated {out.shape[0]}×{out.shape[1]} tokens "

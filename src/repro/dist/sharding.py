@@ -229,29 +229,12 @@ def to_shardings(spec_tree: Any, mesh):
 
 
 def shard_map_dp(f, mesh, in_specs, out_specs, manual_axes: Sequence[str]):
-    """shard_map manual over ``manual_axes`` with the rest auto (GSPMD).
-
-    Bridges the two jax APIs: ``jax.shard_map(..., axis_names=, check_vma=)``
-    (jax ≥ 0.6) and ``jax.experimental.shard_map.shard_map(..., auto=,
-    check_rep=)`` (jax 0.4.x, the pinned toolchain)."""
-    manual = set(manual_axes)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            axis_names=manual,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset(mesh.axis_names) - manual
-    return _shard_map(
+    """shard_map manual over ``manual_axes`` with the rest auto (GSPMD)."""
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=False,
-        auto=auto,
+        axis_names=set(manual_axes),
+        check_vma=False,
     )

@@ -7,7 +7,7 @@ The recurrence (per head, state S ∈ ℝ^{K×V}):
 
 TPU adaptation: instead of a step-by-step scan (serial, VPU-bound), the
 sequence is split into chunks of C tokens; within a chunk everything is
-expressed as MXU matmuls + one O(C²·K) masked elementwise decay tensor, and
+expressed as MXU matmuls + O(C²·K) masked elementwise decay work, and
 the (K, V) state is carried across chunks in VMEM scratch (grid's last
 dimension is sequential on TPU, so scratch persists across chunk steps).
 
@@ -23,6 +23,10 @@ Chunk math (cl = cumsum(log_w) within the chunk, cl_prev = cl shifted):
              = r_t·(u ⊙ k_t)                                (j=t)
     y        = inter + A · v                                (C,C)·(C,V) MXU
     S_out    = diag(exp(cl_C)) S_in + (k ⊙ exp(cl_C−cl))ᵀ · v
+
+A is built one query row at a time (C passes of O(C·K) on the VPU), since
+the chip's kernel compiler has no (C, C, K) broadcast, and cumsum is a
+lower-triangular matmul, since it has no cumsum either.
 """
 from __future__ import annotations
 
@@ -36,7 +40,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _wkv6_kernel(
     r_ref, k_ref, v_ref, lw_ref,  # (1, 1, C, K) VMEM windows
-    u_ref,  # (1, K)
+    u_ref,  # (1, 1, K)
     s0_ref,  # (1, 1, K, V)
     y_ref,  # (1, 1, C, V)
     sout_ref,  # (1, 1, K, V)
@@ -55,10 +59,18 @@ def _wkv6_kernel(
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)  # (C, V)
     lw = lw_ref[0, 0].astype(jnp.float32)  # (C, K), all ≤ 0
-    u = u_ref[0].astype(jnp.float32)  # (K,)
+    u = u_ref[0].astype(jnp.float32)  # (1, K)
     S = state_scr[...]  # (K, V)
 
-    cl = jnp.cumsum(lw, axis=0)  # (C, K)
+    # inclusive cumsum over the chunk as a lower-triangular matmul (Mosaic
+    # has no cumsum): cl[t] = Σ_{i≤t} log w_i
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tril = (col <= row).astype(jnp.float32)
+    cl = jax.lax.dot_general(
+        tril, lw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )  # (C, K)
     cl_prev = cl - lw  # exclusive cumsum: Σ_{i<t} log w_i
 
     # inter-chunk contribution: y_t += (r_t ⊙ W_{t-1}) · S_in
@@ -67,25 +79,26 @@ def _wkv6_kernel(
         r_decay, S, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # (C, V)
 
-    # intra-chunk attention matrix A (C, C): exponent ≤ 0 for j < t
-    diff = cl_prev[:, None, :] - cl[None, :, :]  # (C, C, K)
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    j_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    strict = (j_idx < t_idx)[:, :, None]
-    decay = jnp.where(strict, jnp.exp(jnp.minimum(diff, 0.0)), 0.0)
-    A = jnp.sum(r[:, None, :] * k[None, :, :] * decay, axis=-1)  # (C, C)
-    A = A + jnp.where(
-        t_idx == j_idx, jnp.sum(r * u[None, :] * k, axis=-1)[:, None], 0.0
-    )
+    # intra-chunk attention, built transposed one query row t at a time
+    # (Mosaic has no (C, C, K) broadcast): At[j, t] = A[t, j], exponent ≤ 0
+    At = jnp.where(row == col, jnp.sum(r * u * k, axis=-1, keepdims=True), 0.0)
+    for t in range(1, chunk):
+        decay = jnp.exp(jnp.minimum(cl_prev[t : t + 1] - cl, 0.0))  # (C, K)
+        a_t = jnp.sum(r[t : t + 1] * k * decay, axis=-1, keepdims=True)  # (C, 1)
+        At = At + jnp.where((col == t) & (row < t), a_t, 0.0)
     intra = jax.lax.dot_general(
-        A, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        At, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     y_ref[0, 0] = (inter + intra).astype(y_ref.dtype)
 
     # state update: S_out = diag(exp(cl_C)) S_in + (k ⊙ exp(cl_C − cl))ᵀ · v
-    total = cl[-1]  # (K,)
-    k_decay = k * jnp.exp(total[None, :] - cl)  # (C, K), exponent ≤ 0
-    S_new = jnp.exp(total)[:, None] * S + jax.lax.dot_general(
+    total = cl[chunk - 1 :]  # (1, K)
+    total_col = jax.lax.dot_general(  # the same sums as a (K, 1) column
+        lw, jnp.ones((chunk, 1), jnp.float32), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )
+    k_decay = k * jnp.exp(total - cl)  # (C, K), exponent ≤ 0
+    S_new = jnp.exp(total_col) * S + jax.lax.dot_general(
         k_decay, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     state_scr[...] = S_new
@@ -127,7 +140,9 @@ def wkv6(
             pl.BlockSpec((1, 1, C, K), lambda b, h, t: (b, h, t, 0)),
             pl.BlockSpec((1, 1, C, V), lambda b, h, t: (b, h, t, 0)),
             pl.BlockSpec((1, 1, C, K), lambda b, h, t: (b, h, t, 0)),
-            pl.BlockSpec((1, K), lambda b, h, t: (h, 0)),
+            # u as (H, 1, K): a block's last two dims must equal the
+            # array's or divide by (8, 128), and (1, K) over (H, K) does not
+            pl.BlockSpec((1, 1, K), lambda b, h, t: (h, 0, 0)),
             pl.BlockSpec((1, 1, K, V), lambda b, h, t: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -140,7 +155,7 @@ def wkv6(
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, log_w, u, s0)
+    )(r, k, v, log_w, u[:, None, :], s0)
     if pad:
         y = y[:, :, :T]
     return y, s_fin

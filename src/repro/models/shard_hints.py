@@ -15,7 +15,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _MESH: Optional[Mesh] = None
 _SIZES: dict = {}
@@ -59,15 +59,12 @@ def _apply(x, spec_list):
     """Apply a constraint, dropping axes that are Manual in the current
     tracing context (inside shard_map over the DP axes only the model
     axis remains Auto)."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        manual = {
-            name
-            for name, ty in zip(am.axis_names, am.axis_types)
-            if "Manual" in str(ty)
-        } if am is not None and am.axis_names else set()
-    except Exception:  # noqa: BLE001 — hints must never break tracing
-        manual = set()
+    am = jax.sharding.get_abstract_mesh()
+    manual = {
+        name
+        for name, ty in zip(am.axis_names, am.axis_types)
+        if ty == AxisType.Manual
+    }
 
     def keep(a):
         if a is None:
@@ -80,12 +77,9 @@ def _apply(x, spec_list):
     spec = P(*[keep(a) for a in spec_list])
     if all(a is None for a in spec):
         return x
-    try:
-        if manual:
-            return jax.lax.with_sharding_constraint(x, spec)
-        return jax.lax.with_sharding_constraint(x, NamedSharding(_MESH, spec))
-    except Exception:  # noqa: BLE001
-        return x
+    if manual:
+        return jax.lax.with_sharding_constraint(x, spec)
+    return jax.lax.with_sharding_constraint(x, NamedSharding(_MESH, spec))
 
 
 def hint_bshd(x):

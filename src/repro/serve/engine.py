@@ -6,7 +6,7 @@ same specs the dry-run uses).
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -63,9 +63,12 @@ class ServeEngine:
 
     def generate(
         self, batch_inputs: Dict[str, np.ndarray], max_new_tokens: int
-    ) -> np.ndarray:
+    ) -> Tuple[np.ndarray, np.ndarray]:
         """Greedy generation.  batch_inputs must contain "tokens" (B, S0) and
-        any modality extras the arch needs (frames/patches)."""
+        any modality extras the arch needs (frames/patches).
+
+        Returns the new tokens (B, max_new_tokens) and the fp32 logits
+        (B, V) of the last step, from which the last token was taken."""
         B, S0 = batch_inputs["tokens"].shape
         cache = self.api.init_cache(B, self.s_max)
         batch_inputs = {k: jnp.asarray(v) for k, v in batch_inputs.items()}
@@ -76,4 +79,5 @@ class ServeEngine:
             logits, cache = self._decode(self.params, tok[:, None], cache)
             tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             out.append(tok)
-        return np.stack([np.asarray(t) for t in out], axis=1)
+        tokens = np.stack([np.asarray(t) for t in out], axis=1)
+        return tokens, np.asarray(logits[:, -1])

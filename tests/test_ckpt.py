@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.ckpt.manager import latest_step, restore_checkpoint, save_checkpoint
+from repro.launch.mesh import make_mesh
 
 
 def _state(seed=0):
@@ -55,7 +56,7 @@ def test_restore_with_shardings(tmp_path):
 
     state = _state()
     save_checkpoint(str(tmp_path), 1, state)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shardings = jax.tree_util.tree_map(
         lambda _: NamedSharding(mesh, P()), jax.eval_shape(lambda: state)
     )

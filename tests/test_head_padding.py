@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.models import shard_hints
 from repro.models.attention import _head_pad_plan, gqa_attention
 from repro.models.config import ModelConfig
@@ -74,7 +75,7 @@ def test_padded_attention_exact(hq, hkv, m):
     shard_hints._set_sizes_for_test({"model": m})
     # make active() true without a real mesh: register the host mesh but
     # keep the test sizes (model=m) for the planner
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shard_hints.use_hints(mesh)
     shard_hints._set_sizes_for_test({"model": m, "data": 1})
     padded, _ = gqa_attention(p, x, cfg)

@@ -19,7 +19,7 @@ def test_greedy_matches_full_forward(arch):
     inputs = {k: v for k, v in batch.items() if k != "targets"}
 
     eng = ServeEngine(api, params, batch=B, s_max=S0 + new + 2)
-    out = eng.generate(inputs, max_new_tokens=new)
+    out, _ = eng.generate(inputs, max_new_tokens=new)
     assert out.shape == (B, new)
 
     # oracle: extend token-by-token with full prefill each time
@@ -45,8 +45,8 @@ def test_batch_slots_independent():
     rng = np.random.default_rng(2)
     b2 = make_smoke_batch(cfg, rng=rng, batch=2, seq=8)
     eng2 = ServeEngine(api, params, batch=2, s_max=20)
-    out2 = eng2.generate({"tokens": b2["tokens"]}, max_new_tokens=4)
+    out2, _ = eng2.generate({"tokens": b2["tokens"]}, max_new_tokens=4)
     for row in range(2):
         eng1 = ServeEngine(api, params, batch=1, s_max=20)
-        out1 = eng1.generate({"tokens": b2["tokens"][row : row + 1]}, max_new_tokens=4)
+        out1, _ = eng1.generate({"tokens": b2["tokens"][row : row + 1]}, max_new_tokens=4)
         np.testing.assert_array_equal(out1[0], out2[row])
