@@ -7,6 +7,7 @@ Example (CPU):
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -73,13 +74,19 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=16)
+    ap.add_argument(
+        "--profile-dir", default=None,
+        help="write a profiler trace of the data plane here (XProf / TensorBoard)",
+    )
     args = ap.parse_args()
 
     enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else configs.get_config(args.arch)
-    res = run_serve(
-        cfg, batch=args.batch, prompt_len=args.prompt_len, max_new=args.max_new
-    )
+    profile = jax.profiler.trace(args.profile_dir) if args.profile_dir else contextlib.nullcontext()
+    with profile:
+        res = run_serve(
+            cfg, batch=args.batch, prompt_len=args.prompt_len, max_new=args.max_new
+        )
     out, dt = res["tokens"], res["generate_s"]
     toks = args.batch * args.max_new
     print(f"first call (compile + run) {res['first_call_s']:.2f}s")

@@ -22,6 +22,7 @@ Example:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 from typing import Optional
 
@@ -112,8 +113,10 @@ def run_train(
     pending = None
     t0 = time.perf_counter()
     for i in range(start, steps):
-        batch_i = {k: jnp.asarray(v) for k, v in data.batch_at(i).items()}
-        state, metrics = step_fn(state, batch_i)
+        # a profiler step per train step, for XProf's step view
+        with jax.profiler.StepTraceAnnotation("train", step_num=i):
+            batch_i = {k: jnp.asarray(v) for k, v in data.batch_at(i).items()}
+            state, metrics = step_fn(state, batch_i)
         losses.append(metrics["loss"])
         if i == start:
             jax.block_until_ready(state)
@@ -168,6 +171,10 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--pods", type=int, default=2, help="pods the job occupies")
     ap.add_argument("--log-every", type=int, default=5)
+    ap.add_argument(
+        "--profile-dir", default=None,
+        help="write a profiler trace of the data plane here (XProf / TensorBoard)",
+    )
     args = ap.parse_args()
 
     # ---- control plane ----------------------------------------------------
@@ -182,23 +189,25 @@ def main() -> None:
     # ---- data plane ---------------------------------------------------------
     enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else configs.get_config(args.arch)
-    run_train(
-        cfg,
-        make_host_mesh(),
-        steps=args.steps,
-        batch=args.batch,
-        seq=args.seq,
-        lr=args.lr,
-        hp=TrainHparams(
-            grad_accum=args.grad_accum,
-            hierarchical=args.hierarchical,
-            compress=args.compress,
-            zero1=args.zero1,
-        ),
-        ckpt_dir=args.ckpt_dir,
-        ckpt_every=args.ckpt_every,
-        log_every=args.log_every,
-    )
+    profile = jax.profiler.trace(args.profile_dir) if args.profile_dir else contextlib.nullcontext()
+    with profile:
+        run_train(
+            cfg,
+            make_host_mesh(),
+            steps=args.steps,
+            batch=args.batch,
+            seq=args.seq,
+            lr=args.lr,
+            hp=TrainHparams(
+                grad_accum=args.grad_accum,
+                hierarchical=args.hierarchical,
+                compress=args.compress,
+                zero1=args.zero1,
+            ),
+            ckpt_dir=args.ckpt_dir,
+            ckpt_every=args.ckpt_every,
+            log_every=args.log_every,
+        )
 
 
 if __name__ == "__main__":

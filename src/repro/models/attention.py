@@ -191,18 +191,20 @@ def gqa_attention(
     if cache is not None:
         ck, cv = cache
         if pos is None:  # prefill: write [0:S]
-            ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, 0, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, 0, 0, 0))
+            with jax.named_scope("kv_cache"):
+                ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, 0, 0, 0))
+                cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, 0, 0, 0))
             kv_k, kv_v = k, v
             kv_pos = jnp.arange(S)
             valid = None
         else:  # decode: write at pos, attend over cache
-            ck = jax.lax.dynamic_update_slice(
-                ck, k.astype(ck.dtype), (0, jnp.asarray(pos), 0, 0)
-            )
-            cv = jax.lax.dynamic_update_slice(
-                cv, v.astype(cv.dtype), (0, jnp.asarray(pos), 0, 0)
-            )
+            with jax.named_scope("kv_cache"):
+                ck = jax.lax.dynamic_update_slice(
+                    ck, k.astype(ck.dtype), (0, jnp.asarray(pos), 0, 0)
+                )
+                cv = jax.lax.dynamic_update_slice(
+                    cv, v.astype(cv.dtype), (0, jnp.asarray(pos), 0, 0)
+                )
             kv_k, kv_v = ck.astype(x.dtype), cv.astype(x.dtype)
             kv_pos = jnp.arange(ck.shape[1])
             valid = jnp.asarray(pos) + 1
@@ -302,16 +304,18 @@ def mla_attention(
     if cache is not None:
         cc, cr = cache
         if pos is None:  # prefill
-            cc = jax.lax.dynamic_update_slice(cc, c_kv.astype(cc.dtype), (0, 0, 0))
-            cr = jax.lax.dynamic_update_slice(cr, k_rope.astype(cr.dtype), (0, 0, 0))
+            with jax.named_scope("kv_cache"):
+                cc = jax.lax.dynamic_update_slice(cc, c_kv.astype(cc.dtype), (0, 0, 0))
+                cr = jax.lax.dynamic_update_slice(cr, k_rope.astype(cr.dtype), (0, 0, 0))
             new_cache = (cc, cr)
         else:  # decode over compressed cache (absorbed)
-            cc = jax.lax.dynamic_update_slice(
-                cc, c_kv.astype(cc.dtype), (0, jnp.asarray(pos), 0)
-            )
-            cr = jax.lax.dynamic_update_slice(
-                cr, k_rope.astype(cr.dtype), (0, jnp.asarray(pos), 0)
-            )
+            with jax.named_scope("kv_cache"):
+                cc = jax.lax.dynamic_update_slice(
+                    cc, c_kv.astype(cc.dtype), (0, jnp.asarray(pos), 0)
+                )
+                cr = jax.lax.dynamic_update_slice(
+                    cr, k_rope.astype(cr.dtype), (0, jnp.asarray(pos), 0)
+                )
             new_cache = (cc, cr)
             S_max = cc.shape[1]
             wuk = params["wuk"].astype(x.dtype).reshape(m.kv_lora_rank, h, nope)

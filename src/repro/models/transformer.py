@@ -136,45 +136,51 @@ def _cache_shapes(spec: LayerSpec, cfg, batch: int, s_max: int):
 
 
 def _apply_layer(spec: LayerSpec, p, x, cfg, cache_entry, pos, scan_chunk_size):
+    """One layer.  Each sub-block runs under a ``jax.named_scope`` (``norm``,
+    ``attn``/``mix``, ``mlp``/``moe``) that lands in the compiled ops'
+    ``op_name`` metadata, so a profiler trace can attribute device time to
+    it; the residual add belongs to its sub-block."""
     aux = jnp.zeros((), jnp.float32)
-    h = norm(p["ln1"], x, cfg.norm_kind)
+    with jax.named_scope("norm"):
+        h = norm(p["ln1"], x, cfg.norm_kind)
     if spec.kind == "attn":
         window = spec.window if spec.window else BIG_WINDOW
-        if cfg.attn_kind == "mla":
-            y, new_mix_cache = mla_attention(p["mix"], h, cfg, cache=cache_entry, pos=pos)
-        else:
-            y, new_mix_cache = gqa_attention(
-                p["mix"], h, cfg, window=window, cache=cache_entry, pos=pos
-            )
-        x = x + y
-        h = norm(p["ln2"], x, cfg.norm_kind)
-        if spec.moe:
-            y, aux = moe_mlp(p["ffn"], h, cfg)
-        else:
-            y = mlp(p["ffn"], h, cfg.mlp_kind)
-        x = x + y
-        return x, new_mix_cache, aux
-    if spec.kind == "mamba":
+        with jax.named_scope("attn"):
+            if cfg.attn_kind == "mla":
+                y, new_mix = mla_attention(p["mix"], h, cfg, cache=cache_entry, pos=pos)
+            else:
+                y, new_mix = gqa_attention(
+                    p["mix"], h, cfg, window=window, cache=cache_entry, pos=pos
+                )
+            x = x + y
+    elif spec.kind == "mamba":
         mix_cache = cache_entry[:2] if cache_entry is not None else None
-        y, new_mix = mamba_block(p["mix"], h, cfg, state=mix_cache, chunk=scan_chunk_size)
-        x = x + y
-        h = norm(p["ln2"], x, cfg.norm_kind)
-        if spec.moe:
-            y, aux = moe_mlp(p["ffn"], h, cfg)
-        else:
-            y = mlp(p["ffn"], h, cfg.mlp_kind)
-        x = x + y
-        return x, new_mix, aux
-    if spec.kind == "rwkv":
+        with jax.named_scope("mix"):
+            y, new_mix = mamba_block(p["mix"], h, cfg, state=mix_cache, chunk=scan_chunk_size)
+            x = x + y
+    elif spec.kind == "rwkv":
         tcache = cache_entry[:2] if cache_entry is not None else None
-        y, new_t = rwkv_time_mix(p["mix"], h, cfg, state=tcache, chunk=scan_chunk_size)
-        x = x + y
+        with jax.named_scope("mix"):
+            y, new_mix = rwkv_time_mix(p["mix"], h, cfg, state=tcache, chunk=scan_chunk_size)
+            x = x + y
+    else:
+        raise ValueError(spec.kind)
+    with jax.named_scope("norm"):
         h = norm(p["ln2"], x, cfg.norm_kind)
+    if spec.kind == "rwkv":
         ccache = cache_entry[2] if cache_entry is not None else None
-        y, new_c = rwkv_channel_mix(p["ffn"], h, cfg, state=ccache)
-        x = x + y
-        return x, new_t + (new_c,), aux
-    raise ValueError(spec.kind)
+        with jax.named_scope("mlp"):
+            y, new_c = rwkv_channel_mix(p["ffn"], h, cfg, state=ccache)
+            x = x + y
+        return x, new_mix + (new_c,), aux
+    if spec.moe:
+        with jax.named_scope("moe"):
+            y, aux = moe_mlp(p["ffn"], h, cfg)
+            x = x + y
+    else:
+        with jax.named_scope("mlp"):
+            x = x + mlp(p["ffn"], h, cfg.mlp_kind)
+    return x, new_mix, aux
 
 
 # ---------------------------------------------------------------------------
@@ -263,7 +269,11 @@ def apply_lm(
     elif cache is None:
         raise ValueError(f"mode={mode!r} requires a cache")
     plan = layer_plan(cfg)
-    x = inputs_embeds if inputs_embeds is not None else embed(params["embed"], tokens, cfg)
+    if inputs_embeds is not None:
+        x = inputs_embeds
+    else:
+        with jax.named_scope("embed"):
+            x = embed(params["embed"], tokens, cfg)
     decode = mode == "decode"
     pos = cache["pos"] if decode else None
 
@@ -281,9 +291,10 @@ def apply_lm(
 
         pro_step = _remat_wrap(pro_step, cfg)
         if cache is not None:
-            (x, aux_total), npc = jax.lax.scan(
-                pro_step, (x, aux_total), (params["pro"], cache["pro"])
-            )
+            with jax.named_scope("layers"):
+                (x, aux_total), npc = jax.lax.scan(
+                    pro_step, (x, aux_total), (params["pro"], cache["pro"])
+                )
             new_cache["pro"] = npc
         else:
             def pro_step_nc(carry, p):
@@ -292,7 +303,8 @@ def apply_lm(
                 return (x, aux + a), None
 
             pro_step_nc = _remat_wrap(pro_step_nc, cfg)
-            (x, aux_total), _ = jax.lax.scan(pro_step_nc, (x, aux_total), params["pro"])
+            with jax.named_scope("layers"):
+                (x, aux_total), _ = jax.lax.scan(pro_step_nc, (x, aux_total), params["pro"])
 
     if plan.n_units:
         def unit_step(carry, xs):
@@ -310,9 +322,10 @@ def apply_lm(
 
         if cache is not None:
             step = _remat_wrap(unit_step, cfg)
-            (x, aux_total), nuc = jax.lax.scan(
-                step, (x, aux_total), (params["units"], cache["units"])
-            )
+            with jax.named_scope("layers"):
+                (x, aux_total), nuc = jax.lax.scan(
+                    step, (x, aux_total), (params["units"], cache["units"])
+                )
             new_cache["units"] = nuc
         else:
             def unit_step_nc(carry, p):
@@ -320,16 +333,19 @@ def apply_lm(
                 return (x2, aux2), None
 
             unit_step_nc = _remat_wrap(unit_step_nc, cfg)
-            (x, aux_total), _ = jax.lax.scan(unit_step_nc, (x, aux_total), params["units"])
+            with jax.named_scope("layers"):
+                (x, aux_total), _ = jax.lax.scan(unit_step_nc, (x, aux_total), params["units"])
 
-    x = norm(params["final_norm"], x, cfg.norm_kind)
+    with jax.named_scope("head"):
+        x = norm(params["final_norm"], x, cfg.norm_kind)
     if cache is not None:
         new_cache["pos"] = cache["pos"] + (1 if decode else x.shape[1])
     if return_hidden:
         return x, aux_total, (new_cache if cache is not None else None)
-    if last_only:
-        x = x[:, -1:, :]
-    logits = unembed(params["embed"], x, cfg)
+    with jax.named_scope("head"):
+        if last_only:
+            x = x[:, -1:, :]
+        logits = unembed(params["embed"], x, cfg)
     return logits, aux_total, (new_cache if cache is not None else None)
 
 
@@ -339,9 +355,10 @@ def lm_loss(params, batch, cfg, scan_chunk_size: int = 64):
         params, batch["tokens"], cfg, scan_chunk_size=scan_chunk_size,
         return_hidden=True,
     )
-    loss = cross_entropy_fused(
-        h, params["embed"], batch["targets"], cfg, batch.get("mask")
-    )
+    with jax.named_scope("loss"):
+        loss = cross_entropy_fused(
+            h, params["embed"], batch["targets"], cfg, batch.get("mask")
+        )
     if cfg.moe is not None:
         loss = loss + 0.01 * aux
     return loss

@@ -11,6 +11,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class ServeEngine:
@@ -31,6 +32,7 @@ class ServeEngine:
         self.mesh = mesh
         self._prefill = jax.jit(api.prefill)
         self._decode = jax.jit(api.decode)
+        self._batches = 0  # generate calls so far: the ``batch`` of its spans
 
     def comm_profile(self) -> Dict[str, float]:
         """Measured per-request communication profile of this engine.
@@ -68,16 +70,31 @@ class ServeEngine:
         any modality extras the arch needs (frames/patches).
 
         Returns the new tokens (B, max_new_tokens) and the fp32 logits
-        (B, V) of the last step, from which the last token was taken."""
-        B, S0 = batch_inputs["tokens"].shape
-        cache = self.api.init_cache(B, self.s_max)
-        batch_inputs = {k: jnp.asarray(v) for k, v in batch_inputs.items()}
-        logits, cache = self._prefill(self.params, batch_inputs, cache)
-        tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        out = [tok]
-        for _ in range(max_new_tokens - 1):
-            logits, cache = self._decode(self.params, tok[:, None], cache)
+        (B, V) of the last step, from which the last token was taken.
+
+        Each phase runs in a host span of the profiler's trace
+        (``serve.setup``, ``serve.prefill``, then ``serve.decode`` and
+        ``serve.sample`` per new token after the first, ``serve.collect``),
+        all carrying this call's ``batch`` number and the per-token ones the
+        decode ``step`` (from 0).  With no profiler running a span costs one
+        check."""
+        self._batches += 1
+        n = self._batches
+        with TraceAnnotation("serve.setup", batch=n):
+            B, S0 = batch_inputs["tokens"].shape
+            cache = self.api.init_cache(B, self.s_max)
+            batch_inputs = {k: jnp.asarray(v) for k, v in batch_inputs.items()}
+        with TraceAnnotation("serve.prefill", batch=n):
+            logits, cache = self._prefill(self.params, batch_inputs, cache)
             tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        out = [tok]
+        for i in range(max_new_tokens - 1):
+            with TraceAnnotation("serve.decode", batch=n, step=i):
+                logits, cache = self._decode(self.params, tok[:, None], cache)
+            with TraceAnnotation("serve.sample", batch=n, step=i):
+                tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             out.append(tok)
-        tokens = np.stack([np.asarray(t) for t in out], axis=1)
-        return tokens, np.asarray(logits[:, -1])
+        with TraceAnnotation("serve.collect", batch=n):
+            tokens = np.stack([np.asarray(t) for t in out], axis=1)
+            last = np.asarray(logits[:, -1])
+        return tokens, last

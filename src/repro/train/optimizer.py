@@ -58,7 +58,13 @@ def global_norm(tree: Any) -> jnp.ndarray:
 def adamw_update(
     grads: Any, state: dict, params: Any, opt: OptConfig
 ) -> Tuple[Any, dict, dict]:
-    """Returns (new_params, new_state, metrics)."""
+    """Returns (new_params, new_state, metrics).  Runs under the
+    ``optimizer`` named scope."""
+    with jax.named_scope("optimizer"):
+        return _adamw_update(grads, state, params, opt)
+
+
+def _adamw_update(grads: Any, state: dict, params: Any, opt: OptConfig):
     step = state["step"]
     lr = schedule(opt, step)
     gnorm = global_norm(grads)

@@ -132,3 +132,75 @@ def test_gemma2_local_global_alternation():
     # covered by decode consistency above).
     loss = api.loss(params, batch)
     assert np.isfinite(float(loss))
+
+
+# ---------------------------------------------------------------------------
+# named scopes in the compiled programs
+# ---------------------------------------------------------------------------
+
+def _scopes_in(hlo_text: str) -> set:
+    """Every name-stack component of the compiled module's ``op_name``
+    metadata, with autodiff wrappers taken off: ``transpose(jvp(attn))`` is
+    ``attn``."""
+    import re
+
+    out = set()
+    for path in re.findall(r'op_name="([^"]*)"', hlo_text):
+        for part in path.split("/"):
+            while re.fullmatch(r"[\w.-]+\((.*)\)", part):
+                part = re.fullmatch(r"[\w.-]+\((.*)\)", part).group(1)
+            out.add(part)
+    return out
+
+
+LAYER_SCOPES = {"embed", "layers", "norm", "attn", "mlp", "head"}
+
+
+def _serving_programs(cfg):
+    api = get_api(cfg)
+    params = jax.eval_shape(api.init, jax.random.PRNGKey(0))
+    batch = make_smoke_batch(cfg, batch=2, seq=8)
+    inputs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in batch.items() if k != "targets"}
+    cache = jax.eval_shape(lambda: api.init_cache(2, 16))
+    tok = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    prefill = jax.jit(api.prefill).lower(params, inputs, cache).compile().as_text()
+    decode = jax.jit(api.decode).lower(params, tok, cache).compile().as_text()
+    return prefill, decode
+
+
+def test_compiled_programs_carry_layer_scopes():
+    """Each layer's scope reaches the op_name metadata of the compiled
+    prefill, decode step and train step, and the jit names stay."""
+    from repro.launch.mesh import make_mesh
+    from repro.train.optimizer import OptConfig
+    from repro.train.trainstep import TrainHparams, make_train_state, make_train_step
+
+    cfg = smoke_config("olmo-1b")
+    api = get_api(cfg)
+    prefill, decode = _serving_programs(cfg)
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    sds = {k: jax.ShapeDtypeStruct((2, 16), jnp.int32) for k in ("tokens", "targets")}
+    step, _, _ = make_train_step(api, cfg, OptConfig(), mesh, TrainHparams(), sds)
+    state = jax.eval_shape(lambda: make_train_state(api, jax.random.PRNGKey(0)))
+    train = step.lower(state, sds).compile().as_text()
+
+    assert prefill.startswith("HloModule jit_prefill,")
+    assert decode.startswith("HloModule jit_decode_step,")
+    assert train.startswith("HloModule jit_step,")
+    for text in (prefill, decode):
+        assert LAYER_SCOPES | {"kv_cache"} <= _scopes_in(text)
+    assert LAYER_SCOPES | {"loss", "optimizer"} <= _scopes_in(train)
+
+
+@pytest.mark.parametrize(
+    "arch, scopes",
+    [
+        ("deepseek-v3-671b", {"attn", "kv_cache", "moe"}),
+        ("jamba-1.5-large-398b", {"attn", "kv_cache", "mix", "moe"}),
+        ("rwkv6-1.6b", {"mix", "mlp"}),
+    ],
+)
+def test_other_families_carry_their_scopes(arch, scopes):
+    """MLA's cache, experts, and mamba / RWKV mixing get their own scopes."""
+    for text in _serving_programs(smoke_config(arch)):
+        assert {"embed", "layers", "norm", "head"} | scopes <= _scopes_in(text)

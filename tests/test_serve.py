@@ -50,3 +50,33 @@ def test_batch_slots_independent():
         eng1 = ServeEngine(api, params, batch=1, s_max=20)
         out1, _ = eng1.generate({"tokens": b2["tokens"][row : row + 1]}, max_new_tokens=4)
         np.testing.assert_array_equal(out1[0], out2[row])
+
+
+def test_generate_writes_its_spans(tmp_path):
+    """Under the profiler, generate leaves serve.* host spans in the trace:
+    one serve.decode and one serve.sample per new token after the first, the
+    decode steps numbered in order, and every span of the call tagged with
+    the same batch."""
+    import collections
+
+    cfg = smoke_config("olmo-1b")
+    api = get_api(cfg)
+    eng = ServeEngine(api, api.init(jax.random.PRNGKey(0)), batch=2, s_max=16)
+    tokens = {"tokens": np.zeros((2, 8), np.int32)}
+    new = 5
+    eng.generate(tokens, new)  # compiles outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        eng.generate(tokens, new)
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    spans = collections.defaultdict(list)
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    spans[ev.name].append(dict(ev.stats))
+    assert set(spans) == {"serve.setup", "serve.prefill", "serve.decode", "serve.sample", "serve.collect"}
+    for name in ("serve.setup", "serve.prefill", "serve.collect"):
+        assert len(spans[name]) == 1
+    assert [s["step"] for s in spans["serve.decode"]] == list(range(new - 1))
+    assert [s["step"] for s in spans["serve.sample"]] == list(range(new - 1))
+    assert {s["batch"] for stats in spans.values() for s in stats} == {2}

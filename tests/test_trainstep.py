@@ -118,3 +118,67 @@ def test_train_resume_from_checkpoint(tmp_path):
         state2, m = step(state2, batch)
         resumed.append(float(m["loss"]))
     np.testing.assert_allclose(resumed, ref_losses[3:], rtol=1e-5)
+
+
+def test_hierarchical_step_names_its_collectives(tmp_path):
+    """On four devices (pod 2 x data 2) the hierarchical step compiles to a
+    module named jit_step, like the pjit step, and its collectives carry the
+    grad_sync/{reduce_scatter,pod_allreduce,all_gather} scopes.  XLA_FLAGS
+    must be set before jax is imported, hence the child process."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    script = tmp_path / "hier_scopes.py"
+    script.write_text(
+        """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
+from repro.models import get_api, smoke_config
+from repro.train.optimizer import OptConfig
+from repro.train.trainstep import TrainHparams, make_train_state, make_train_step
+cfg = smoke_config("olmo-1b")
+api = get_api(cfg)
+mesh = make_mesh((2, 2, 1), ("pod", "data", "model"))
+sds = {k: jax.ShapeDtypeStruct((8, 16), jnp.int32) for k in ("tokens", "targets")}
+hp = TrainHparams(hierarchical=True, zero1=True, compress=True)
+step, _, _ = make_train_step(api, cfg, OptConfig(), mesh, hp, sds)
+state = jax.eval_shape(lambda: make_train_state(api, jax.random.PRNGKey(0)))
+print(step.lower(state, sds).compile().as_text())
+"""
+    )
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    r = subprocess.run(
+        [sys.executable, str(script)], capture_output=True, text=True, timeout=600, env=env
+    )
+    assert r.returncode == 0, r.stderr[-2000:]
+    hlo = r.stdout
+    assert hlo.startswith("HloModule jit_step,")
+    for scope in ("grad_sync/reduce_scatter", "grad_sync/pod_allreduce", "grad_sync/all_gather"):
+        assert scope + "/" in hlo, scope
+    assert "optimizer/" in hlo
+
+
+def test_run_train_marks_each_step_for_the_profiler(tmp_path):
+    """Under the profiler, run_train leaves one ``train`` step annotation per
+    step, numbered by the step, for XProf's step view."""
+    from repro.launch.mesh import make_host_mesh
+    from repro.launch.train import run_train
+
+    cfg = smoke_config("olmo-1b")
+    with jax.profiler.trace(str(tmp_path)):
+        run_train(cfg, make_host_mesh(), steps=3, batch=2, seq=16, log_every=10)
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    steps = [
+        dict(ev.stats)["step_num"]
+        for plane in jax.profiler.ProfileData.from_file(str(path)).planes
+        for line in plane.lines
+        for ev in line.events
+        if ev.name == "train"
+    ]
+    assert steps == [0, 1, 2]
