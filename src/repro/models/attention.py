@@ -5,11 +5,14 @@ and cross-attention (whisper).
 All functions are cache-polymorphic:
 
 * ``cache=None``            — training / scoring over a full sequence
-* ``cache=(…), pos=None``   — prefill: full sequence, cache slices written
-* ``cache=(…), pos=scalar`` — decode: single-token step, cache updated
+* ``cache=(…), pos=None``   — prefill: full sequence, cache rows [0:S] written
+* ``cache=(…), pos=scalar`` — decode: single-token step, cache row pos written
 
-Shapes: x (B, S, d); GQA cache k/v (B, S_max, Hkv, Dh); MLA cache
-(c_kv (B, S_max, R), k_rope (B, S_max, Dr)).
+A cache is stacked over the layers of a scan, and the layer owns index
+``layer`` of it: it writes only its new rows at ``(layer, 0, start)`` and, in
+decode, reads its K/V back from there.  Shapes: x (B, S, d); GQA cache k/v
+(L, B, S_max, Hkv, Dh); MLA cache (c_kv (L, B, S_max, R), k_rope
+(L, B, S_max, Dr)).
 """
 from __future__ import annotations
 
@@ -18,6 +21,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from . import shard_hints
 from .layers import apply_rope, dense_init, norm, softcap
@@ -38,6 +42,24 @@ def _mask_bias(q_pos, kv_pos, window, valid_len=None):
     if valid_len is not None:
         ok &= k < valid_len
     return jnp.where(ok, 0.0, -1e30).astype(jnp.float32)
+
+
+def _write_rows(buf, rows, layer, start):
+    """Write ``rows`` (B, S, ...) into the stacked cache ``buf``
+    (L, B, S_max, ...) at layer ``layer``, positions ``start`` onward."""
+    idx = (layer, 0, start) + (0,) * (rows.ndim - 2)
+    return jax.lax.dynamic_update_slice(buf, rows[None].astype(buf.dtype), idx)
+
+
+def _read_layer(bufs, layer):
+    """Returns ``(bufs, rows)``: the stacked buffers that decode has just
+    written its row to, and layer ``layer``'s (B, S_max, ...) part of each.
+
+    The buffers keep the step argument's row-major layout.  Left free, the
+    TPU compiler lays the scan's carried cache out for this read, and copies
+    the whole cache in and out of the scan on every step."""
+    bufs = tuple(with_layout_constraint(b, Layout(tuple(range(b.ndim)))) for b in bufs)
+    return bufs, tuple(jax.lax.dynamic_index_in_dim(b, layer, 0, keepdims=False) for b in bufs)
 
 
 ATTN_Q_CHUNK = 1024  # flash-pattern query blocking for the XLA path
@@ -159,10 +181,12 @@ def gqa_attention(
     window=None,
     causal: bool = True,
     cache: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
+    layer: Optional[jnp.ndarray] = None,
     pos: Optional[jnp.ndarray] = None,
 ):
     """Returns (y, new_cache).  ``window``: None→cfg/sliding default handling
-    is done by the caller (pass an int or traced scalar)."""
+    is done by the caller (pass an int or traced scalar).  ``cache`` is the
+    stacked (k, v) of which this layer owns index ``layer``."""
     B, S, d = x.shape
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     g = hq // hkv
@@ -188,31 +212,21 @@ def gqa_attention(
         k = apply_rope(k, q_pos, cfg.rope_theta)
 
     new_cache = None
+    kv_k, kv_v = k, v  # train / prefill: attend over the fresh rows
+    kv_pos = jnp.arange(S)
+    valid = None
     if cache is not None:
-        ck, cv = cache
-        if pos is None:  # prefill: write [0:S]
-            with jax.named_scope("kv_cache"):
-                ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, 0, 0, 0))
-                cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, 0, 0, 0))
-            kv_k, kv_v = k, v
-            kv_pos = jnp.arange(S)
-            valid = None
-        else:  # decode: write at pos, attend over cache
-            with jax.named_scope("kv_cache"):
-                ck = jax.lax.dynamic_update_slice(
-                    ck, k.astype(ck.dtype), (0, jnp.asarray(pos), 0, 0)
-                )
-                cv = jax.lax.dynamic_update_slice(
-                    cv, v.astype(cv.dtype), (0, jnp.asarray(pos), 0, 0)
-                )
-            kv_k, kv_v = ck.astype(x.dtype), cv.astype(x.dtype)
-            kv_pos = jnp.arange(ck.shape[1])
-            valid = jnp.asarray(pos) + 1
-        new_cache = (ck, cv)
-    else:
-        kv_k, kv_v = k, v
-        kv_pos = jnp.arange(S)
-        valid = None
+        start = 0 if pos is None else jnp.asarray(pos)
+        with jax.named_scope("kv_cache"):
+            new_cache = (
+                _write_rows(cache[0], k, layer, start),
+                _write_rows(cache[1], v, layer, start),
+            )
+        if pos is not None:  # decode: attend over this layer's whole cache
+            new_cache, (kv_k, kv_v) = _read_layer(new_cache, layer)
+            kv_k, kv_v = kv_k.astype(x.dtype), kv_v.astype(x.dtype)
+            kv_pos = jnp.arange(kv_k.shape[1])
+            valid = start + 1
 
     scale = 1.0 / math.sqrt(hd)
     pad = (
@@ -274,10 +288,13 @@ def mla_attention(
     cfg,
     *,
     cache: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
+    layer: Optional[jnp.ndarray] = None,
     pos: Optional[jnp.ndarray] = None,
 ):
     """MLA.  Train/prefill uses the expanded form; decode uses the absorbed
-    form over the compressed cache (c_kv, k_rope) — the MLA memory win."""
+    form over the compressed cache (c_kv, k_rope) — the MLA memory win.
+    ``cache`` is the stacked (c_kv, k_rope) of which this layer owns index
+    ``layer``."""
     m = cfg.mla
     B, S, d = x.shape
     h = cfg.num_heads
@@ -302,21 +319,15 @@ def mla_attention(
 
     new_cache = None
     if cache is not None:
-        cc, cr = cache
-        if pos is None:  # prefill
-            with jax.named_scope("kv_cache"):
-                cc = jax.lax.dynamic_update_slice(cc, c_kv.astype(cc.dtype), (0, 0, 0))
-                cr = jax.lax.dynamic_update_slice(cr, k_rope.astype(cr.dtype), (0, 0, 0))
-            new_cache = (cc, cr)
-        else:  # decode over compressed cache (absorbed)
-            with jax.named_scope("kv_cache"):
-                cc = jax.lax.dynamic_update_slice(
-                    cc, c_kv.astype(cc.dtype), (0, jnp.asarray(pos), 0)
-                )
-                cr = jax.lax.dynamic_update_slice(
-                    cr, k_rope.astype(cr.dtype), (0, jnp.asarray(pos), 0)
-                )
-            new_cache = (cc, cr)
+        start = 0 if pos is None else jnp.asarray(pos)
+        with jax.named_scope("kv_cache"):
+            new_cache = (
+                _write_rows(cache[0], c_kv, layer, start),
+                _write_rows(cache[1], k_rope, layer, start),
+            )
+
+        if pos is not None:  # decode over compressed cache (absorbed)
+            new_cache, (cc, cr) = _read_layer(new_cache, layer)
             S_max = cc.shape[1]
             wuk = params["wuk"].astype(x.dtype).reshape(m.kv_lora_rank, h, nope)
             # absorb W_uk into q:  (B,1,h,nope)·(r,h,nope) -> (B,1,h,r)

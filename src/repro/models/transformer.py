@@ -8,7 +8,9 @@ is what makes the 61-layer DeepSeek dry-run compile in seconds.
 
 Caches mirror the layer plan: each unit element owns a cache entry stacked
 over units; ``init_cache`` builds the pytree, prefill writes it, decode
-updates it in place (functionally).
+updates it.  The stacked cache rides in the layer scan's carry
+(``scan_cached``) and each layer writes only what it changes at its own
+index, so a step whose cache is donated updates it in place.
 """
 from __future__ import annotations
 
@@ -135,51 +137,76 @@ def _cache_shapes(spec: LayerSpec, cfg, batch: int, s_max: int):
     raise ValueError(spec.kind)
 
 
-def _apply_layer(spec: LayerSpec, p, x, cfg, cache_entry, pos, scan_chunk_size):
+def _layer_state(cache, layer):
+    """Layer ``layer``'s entry of a stacked recurrent state."""
+    return tuple(jax.lax.dynamic_index_in_dim(b, layer, 0, keepdims=False) for b in cache)
+
+
+def _put_layer_state(cache, state, layer):
+    """Replace layer ``layer``'s entry of a stacked recurrent state."""
+    with jax.named_scope("kv_cache"):
+        return tuple(
+            jax.lax.dynamic_update_index_in_dim(b, s.astype(b.dtype), layer, 0)
+            for b, s in zip(cache, state)
+        )
+
+
+def _apply_layer(spec: LayerSpec, p, x, cfg, cache, layer, pos, scan_chunk_size):
     """One layer.  Each sub-block runs under a ``jax.named_scope`` (``norm``,
     ``attn``/``mix``, ``mlp``/``moe``) that lands in the compiled ops'
     ``op_name`` metadata, so a profiler trace can attribute device time to
-    it; the residual add belongs to its sub-block."""
+    it; the residual add belongs to its sub-block.
+
+    ``cache`` is this layer's entry stacked over the layers of its scan, of
+    which it owns index ``layer`` (None in training).  Returns the stacked
+    entry with this layer's part updated: attention writes its new K/V rows
+    (the stack grows with context), mamba and RWKV replace their whole
+    fixed-size state."""
     aux = jnp.zeros((), jnp.float32)
+    state = _layer_state(cache, layer) if cache is not None and spec.kind != "attn" else None
     with jax.named_scope("norm"):
         h = norm(p["ln1"], x, cfg.norm_kind)
     if spec.kind == "attn":
         window = spec.window if spec.window else BIG_WINDOW
         with jax.named_scope("attn"):
             if cfg.attn_kind == "mla":
-                y, new_mix = mla_attention(p["mix"], h, cfg, cache=cache_entry, pos=pos)
+                y, new_mix = mla_attention(
+                    p["mix"], h, cfg, cache=cache, layer=layer, pos=pos
+                )
             else:
                 y, new_mix = gqa_attention(
-                    p["mix"], h, cfg, window=window, cache=cache_entry, pos=pos
+                    p["mix"], h, cfg, window=window, cache=cache, layer=layer, pos=pos
                 )
             x = x + y
     elif spec.kind == "mamba":
-        mix_cache = cache_entry[:2] if cache_entry is not None else None
+        mix_state = state[:2] if state is not None else None
         with jax.named_scope("mix"):
-            y, new_mix = mamba_block(p["mix"], h, cfg, state=mix_cache, chunk=scan_chunk_size)
+            y, new_mix = mamba_block(p["mix"], h, cfg, state=mix_state, chunk=scan_chunk_size)
             x = x + y
     elif spec.kind == "rwkv":
-        tcache = cache_entry[:2] if cache_entry is not None else None
+        tstate = state[:2] if state is not None else None
         with jax.named_scope("mix"):
-            y, new_mix = rwkv_time_mix(p["mix"], h, cfg, state=tcache, chunk=scan_chunk_size)
+            y, new_mix = rwkv_time_mix(p["mix"], h, cfg, state=tstate, chunk=scan_chunk_size)
             x = x + y
     else:
         raise ValueError(spec.kind)
     with jax.named_scope("norm"):
         h = norm(p["ln2"], x, cfg.norm_kind)
     if spec.kind == "rwkv":
-        ccache = cache_entry[2] if cache_entry is not None else None
+        cstate = state[2] if state is not None else None
         with jax.named_scope("mlp"):
-            y, new_c = rwkv_channel_mix(p["ffn"], h, cfg, state=ccache)
+            y, new_c = rwkv_channel_mix(p["ffn"], h, cfg, state=cstate)
             x = x + y
-        return x, new_mix + (new_c,), aux
-    if spec.moe:
+        new_mix = new_mix + (new_c,)
+    elif spec.moe:
         with jax.named_scope("moe"):
             y, aux = moe_mlp(p["ffn"], h, cfg)
             x = x + y
     else:
         with jax.named_scope("mlp"):
             x = x + mlp(p["ffn"], h, cfg.mlp_kind)
+    if state is not None:
+        new_mix = _put_layer_state(cache, new_mix, layer)
     return x, new_mix, aux
 
 
@@ -247,6 +274,27 @@ def _remat_wrap(fn, cfg):
     raise ValueError(cfg.remat_policy)
 
 
+def scan_cached(step, carry, params, cache):
+    """Scan ``step(carry, p, cache, i) -> (carry, cache)`` over the layers
+    stacked on the leading axis of ``params``, with the stacked ``cache`` in
+    the scan's carry beside ``carry``; ``i`` is the layer's index.
+
+    Each layer writes only what it changes into ``cache`` at its index, so
+    the cache is neither sliced out of the scan's ``xs`` nor stacked anew
+    into its ``ys``.  When the jitted step donates the cache, XLA aliases it
+    to the returned cache and updates it in place.  Returns (carry, cache).
+    """
+    def body(c, p):
+        carry, cache, i = c
+        carry, cache = step(carry, p, cache, i)
+        return (carry, cache, i + 1), None
+
+    (carry, cache, _), _ = jax.lax.scan(
+        body, (carry, cache, jnp.zeros((), jnp.int32)), params
+    )
+    return carry, cache
+
+
 def apply_lm(
     params: dict,
     tokens: Optional[jnp.ndarray],
@@ -283,57 +331,45 @@ def apply_lm(
     if plan.prologue:
         spec = plan.prologue[0]
 
-        def pro_step(carry, xs):
+        def pro_step(carry, p, c=None, i=None):
             x, aux = carry
-            p, c = xs
-            x, nc, a = _apply_layer(spec, p, x, cfg, c, pos, scan_chunk_size)
+            x, nc, a = _apply_layer(spec, p, x, cfg, c, i, pos, scan_chunk_size)
             return (x, aux + a), nc
 
-        pro_step = _remat_wrap(pro_step, cfg)
-        if cache is not None:
-            with jax.named_scope("layers"):
-                (x, aux_total), npc = jax.lax.scan(
-                    pro_step, (x, aux_total), (params["pro"], cache["pro"])
+        with jax.named_scope("layers"):
+            if cache is not None:
+                (x, aux_total), new_cache["pro"] = scan_cached(
+                    pro_step, (x, aux_total), params["pro"], cache["pro"]
                 )
-            new_cache["pro"] = npc
-        else:
-            def pro_step_nc(carry, p):
-                x, aux = carry
-                x, _, a = _apply_layer(spec, p, x, cfg, None, pos, scan_chunk_size)
-                return (x, aux + a), None
+            else:
+                def pro_step_nc(carry, p):
+                    return pro_step(carry, p)[0], None
 
-            pro_step_nc = _remat_wrap(pro_step_nc, cfg)
-            with jax.named_scope("layers"):
+                pro_step_nc = _remat_wrap(pro_step_nc, cfg)
                 (x, aux_total), _ = jax.lax.scan(pro_step_nc, (x, aux_total), params["pro"])
 
     if plan.n_units:
-        def unit_step(carry, xs):
+        def unit_step(carry, p, c=None, i=None):
             x, aux = carry
-            p, c = xs
             ncs = {}
-            for i, s in enumerate(plan.unit):
-                x, nc, a = _apply_layer(
-                    s, p[f"l{i}"], x, cfg, c[f"l{i}"] if c is not None else None,
-                    pos, scan_chunk_size,
+            for j, s in enumerate(plan.unit):
+                x, ncs[f"l{j}"], a = _apply_layer(
+                    s, p[f"l{j}"], x, cfg, c[f"l{j}"] if c is not None else None,
+                    i, pos, scan_chunk_size,
                 )
-                ncs[f"l{i}"] = nc
                 aux = aux + a
             return (x, aux), ncs
 
-        if cache is not None:
-            step = _remat_wrap(unit_step, cfg)
-            with jax.named_scope("layers"):
-                (x, aux_total), nuc = jax.lax.scan(
-                    step, (x, aux_total), (params["units"], cache["units"])
+        with jax.named_scope("layers"):
+            if cache is not None:
+                (x, aux_total), new_cache["units"] = scan_cached(
+                    unit_step, (x, aux_total), params["units"], cache["units"]
                 )
-            new_cache["units"] = nuc
-        else:
-            def unit_step_nc(carry, p):
-                (x2, aux2), _ = unit_step((carry[0], carry[1]), (p, None))
-                return (x2, aux2), None
+            else:
+                def unit_step_nc(carry, p):
+                    return unit_step(carry, p)[0], None
 
-            unit_step_nc = _remat_wrap(unit_step_nc, cfg)
-            with jax.named_scope("layers"):
+                unit_step_nc = _remat_wrap(unit_step_nc, cfg)
                 (x, aux_total), _ = jax.lax.scan(unit_step_nc, (x, aux_total), params["units"])
 
     with jax.named_scope("head"):

@@ -22,6 +22,7 @@ from .layers import (
     mlp,
     norm,
 )
+from .transformer import _remat_wrap, scan_cached
 
 
 def _sinusoid(seq: int, dim: int) -> jnp.ndarray:
@@ -73,8 +74,6 @@ def init_whisper(key, cfg, max_target_positions: int = 448) -> dict:
 
 def encode(params: dict, frames: jnp.ndarray, cfg) -> jnp.ndarray:
     """frames: (B, S_enc, d) precomputed conv-frontend output (stub)."""
-    from .transformer import _remat_wrap
-
     x = frames.astype(cfg.cdtype) + _sinusoid(frames.shape[1], cfg.d_model).astype(
         cfg.cdtype
     )
@@ -111,11 +110,9 @@ def decode(
         pe = params["pos"][:S]
     x = x + pe.astype(cfg.cdtype)[None]
 
-    def step(carry, xs):
-        x = carry
-        p, c = xs
+    def step(x, p, c=None, i=None):
         h = norm(p["ln1"], x, cfg.norm_kind)
-        y, nc = gqa_attention(p["attn"], h, cfg, cache=c, pos=pos)
+        y, nc = gqa_attention(p["attn"], h, cfg, cache=c, layer=i, pos=pos)
         x = x + y
         h = norm(p["lnx"], x, cfg.norm_kind)
         x = x + cross_attention(p["xattn"], h, enc_out, cfg)
@@ -123,22 +120,12 @@ def decode(
         x = x + mlp(p["ffn"], h, cfg.mlp_kind)
         return x, nc
 
-    from .transformer import _remat_wrap
-
     if cache is not None:
-        x, nkv = jax.lax.scan(
-            _remat_wrap(step, cfg), x, (params["dec_layers"], cache["kv"])
-        )
+        x, nkv = scan_cached(step, x, params["dec_layers"], cache["kv"])
         new_cache = {"pos": cache["pos"] + (1 if decode_mode else S), "kv": nkv}
     else:
         def step_nc(x, p):
-            h = norm(p["ln1"], x, cfg.norm_kind)
-            y, _ = gqa_attention(p["attn"], h, cfg)
-            x = x + y
-            h = norm(p["lnx"], x, cfg.norm_kind)
-            x = x + cross_attention(p["xattn"], h, enc_out, cfg)
-            h = norm(p["ln2"], x, cfg.norm_kind)
-            return x + mlp(p["ffn"], h, cfg.mlp_kind), None
+            return step(x, p)[0], None
 
         x, _ = jax.lax.scan(_remat_wrap(step_nc, cfg), x, params["dec_layers"])
         new_cache = None

@@ -2,11 +2,12 @@
 
 Mirrors a production continuous-batching server in miniature: fixed batch
 slots, one jitted prefill and one jitted decode step (both shardable with the
-same specs the dry-run uses).
+same specs the dry-run uses).  Both steps take the KV cache donated and
+update it in place.
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,9 +31,34 @@ class ServeEngine:
         self.batch = batch
         self.s_max = s_max
         self.mesh = mesh
-        self._prefill = jax.jit(api.prefill)
-        self._decode = jax.jit(api.decode)
+        # The cache (argument 2) is donated: the layer scan carries it and
+        # writes only the new rows, so XLA aliases it to the returned cache
+        # and neither step allocates a second one.
+        self._prefill = jax.jit(api.prefill, donate_argnums=2)
+        self._decode = jax.jit(api.decode, donate_argnums=2)
         self._batches = 0  # generate calls so far: the ``batch`` of its spans
+
+    def compiled_steps(self, batch_inputs: Dict[str, Any]) -> Dict[str, Any]:
+        """The prefill and decode steps compiled for ``batch_inputs`` (as
+        ``generate`` takes them; arrays or shape structs), without running
+        them.  Compiles anew: keep it out of a timed path."""
+        inputs = {k: jax.ShapeDtypeStruct(np.shape(v), v.dtype) for k, v in batch_inputs.items()}
+        B = inputs["tokens"].shape[0]
+        cache = jax.eval_shape(lambda: self.api.init_cache(B, self.s_max))
+        tok = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+        return {
+            "prefill": self._prefill.lower(self.params, inputs, cache).compile(),
+            "decode": self._decode.lower(self.params, tok, cache).compile(),
+        }
+
+    def cache_alias_bytes(self, batch_inputs: Dict[str, Any]) -> Dict[str, int]:
+        """Bytes each compiled step (``prefill``, ``decode``) aliases from
+        its cache input to its output: the cache's own bytes when the step
+        updates the cache in place, 0 when it copies it."""
+        return {
+            name: int(step.memory_analysis().alias_size_in_bytes)
+            for name, step in self.compiled_steps(batch_inputs).items()
+        }
 
     def comm_profile(self) -> Dict[str, float]:
         """Measured per-request communication profile of this engine.

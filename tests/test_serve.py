@@ -8,8 +8,25 @@ from repro.models import get_api, make_smoke_batch, smoke_config
 from repro.serve.engine import ServeEngine
 
 
-@pytest.mark.parametrize("arch", ["olmo-1b", "rwkv6-1.6b", "whisper-small"])
-def test_greedy_matches_full_forward(arch):
+@pytest.mark.parametrize(
+    "arch, calls",
+    [
+        pytest.param("olmo-1b", 1, id="olmo-1b"),
+        pytest.param("rwkv6-1.6b", 1, id="rwkv6-1.6b"),
+        pytest.param("whisper-small", 1, id="whisper-small"),
+        # MLA, a dense prologue before the MoE units
+        pytest.param("deepseek-v3-671b", 1, id="deepseek-v3-671b"),
+        # attention and mamba in one unit
+        pytest.param("jamba-1.5-large-398b", 1, id="jamba-1.5-large-398b"),
+        # local and global attention alternate
+        pytest.param("gemma2-9b", 1, id="gemma2-9b"),
+        # a vision prefix in the cache
+        pytest.param("internvl2-1b", 1, id="internvl2-1b"),
+        # one engine, twice: a reused donated buffer raises
+        pytest.param("olmo-1b", 2, id="olmo-1b-twice"),
+    ],
+)
+def test_greedy_matches_full_forward(arch, calls):
     cfg = smoke_config(arch)
     api = get_api(cfg)
     params = api.init(jax.random.PRNGKey(0))
@@ -21,6 +38,9 @@ def test_greedy_matches_full_forward(arch):
     eng = ServeEngine(api, params, batch=B, s_max=S0 + new + 2)
     out, _ = eng.generate(inputs, max_new_tokens=new)
     assert out.shape == (B, new)
+    for _ in range(calls - 1):
+        again, _ = eng.generate(inputs, max_new_tokens=new)
+        np.testing.assert_array_equal(again, out)
 
     # oracle: extend token-by-token with full prefill each time
     import jax.numpy as jnp
@@ -80,3 +100,27 @@ def test_generate_writes_its_spans(tmp_path):
     assert [s["step"] for s in spans["serve.decode"]] == list(range(new - 1))
     assert [s["step"] for s in spans["serve.sample"]] == list(range(new - 1))
     assert {s["batch"] for stats in spans.values() for s in stats} == {2}
+
+
+def test_serving_steps_update_the_cache_in_place():
+    """The compiled prefill and decode steps alias the whole donated cache to
+    the cache they return, and hold no second copy of it: their temporaries
+    are one layer's working set, the same at 4 layers as at 16.  (The xs/ys
+    layer scan, donated, kept a whole copy: 502,560 temp bytes at 4 layers.)"""
+    B, s_max = 4, 64
+    inputs = {"tokens": np.zeros((B, 8), np.int32)}
+    nbytes, temp = {}, {}
+    for layers in (4, 16):
+        api = get_api(smoke_config("olmo-1b").replace(num_layers=layers))
+        eng = ServeEngine(api, jax.eval_shape(api.init, jax.random.PRNGKey(0)), batch=B, s_max=s_max)
+        cache = jax.eval_shape(lambda: api.init_cache(B, s_max))
+        nbytes[layers] = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(cache))
+        assert eng.cache_alias_bytes(inputs) == {"prefill": nbytes[layers], "decode": nbytes[layers]}
+        temp[layers] = {
+            name: step.memory_analysis().temp_size_in_bytes
+            for name, step in eng.compiled_steps(inputs).items()
+        }
+    assert nbytes[4] == 262_148
+    assert max(temp[4].values()) < nbytes[4]
+    assert temp[16] == temp[4]
+    assert max(temp[16].values()) < nbytes[16] / 2

@@ -1,12 +1,15 @@
 """Compiles for a described TPU v5e (nothing runs): the Pallas kernels at
-the widths the models use, and olmo-1b's decode and train steps at the sizes
-``chip_smoke.py`` runs on one chip.  The chip's compiler refuses block
+the widths the models use, olmo-1b's decode and train steps at the sizes
+``chip_smoke.py`` runs on one chip, and its serving steps at the benchmark's
+serving sizes.  The chip's compiler refuses block
 shapes that break its tiling and programs that do not fit its memory, which
 interpret mode and the CPU backend never check.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process may load the TPU library, and pytest workers
 import every test file."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -88,6 +91,26 @@ def test_olmo_decode_step_compiles_full_config(one_chip):
     tok = _sds((8, 1), jnp.int32, one_chip)
     compiled = jax.jit(api.decode).lower(params, tok, cache).compile()
     assert compiled.memory_analysis().argument_size_in_bytes > 2e9
+
+
+@pytest.mark.parametrize("batch, prompt, new", [(32, 512, 128), (8, 2016, 8)])
+def test_olmo_serving_steps_update_the_cache_in_place(one_chip, batch, prompt, new):
+    """The serving cells' prefill and decode, with the cache donated as
+    ``ServeEngine`` donates it: the whole cache is aliased to the returned
+    one and the chip holds no second copy of it, nor relays it out."""
+    api = get_api(configs.get_config("olmo-1b"))
+    params = _on(jax.eval_shape(api.init, jax.random.PRNGKey(0)), one_chip)
+    cache = _on(jax.eval_shape(lambda: api.init_cache(batch, prompt + new + 2)), one_chip)
+    nbytes = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(cache))
+    inputs = {"tokens": _sds((batch, prompt), jnp.int32, one_chip)}
+    tok = _sds((batch, 1), jnp.int32, one_chip)
+    kv_shape = ",".join(map(str, cache["units"]["l0"][0].shape))
+    for step, args in ((api.prefill, (params, inputs, cache)), (api.decode, (params, tok, cache))):
+        compiled = jax.jit(step, donate_argnums=2).lower(*args).compile()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= nbytes  # pos pads to one tile
+        assert mem.temp_size_in_bytes < nbytes / 2, mem.temp_size_in_bytes
+        assert not re.search(rf"bf16\[{kv_shape}\]\{{[^}}]*\}} copy\(", compiled.as_text())
 
 
 def test_olmo_train_step_fits_one_chip_at_8_layers(topo):
