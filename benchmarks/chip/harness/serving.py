@@ -48,7 +48,8 @@ def build(run: Run, break_engine=None):
 def run_cell(run: Run, break_engine=None) -> Dict:
     t = run.cell.traffic
     B, Pn, N = t["batch"], t["prompt"], t["new"]
-    vocab = run.token_vocab()
+    family = run.reference()
+    vocab, served_vocab = family.vocab(run.cell.config)
     with jax.default_device(run.devices[0]):
         eng = build(run, break_engine)
         # warm every shape the window uses: one generate of the cell's size
@@ -74,9 +75,10 @@ def run_cell(run: Run, break_engine=None) -> Dict:
     run.free_program()
 
     served_all = np.concatenate(served, axis=0)
-    bad = ~((served_all >= 0) & (served_all < int(run.cell.config["embedding_size"]))).all(axis=1)
-    sizes = flops.model_sizes(run.cell.config)
-    dec_f, dec_b = flops.generate_decode_mean(sizes, B, Pn, N)
+    bad = ~((served_all >= 0) & (served_all < served_vocab)).all(axis=1)
+    dec_f, dec_b = flops.generate_decode_mean(
+        lambda context: family.decode_step_work(run.cell.config, B, context), Pn, N
+    )
     n_req = len(latencies)
     return {
         "attempted": n_req,
@@ -86,7 +88,7 @@ def run_cell(run: Run, break_engine=None) -> Dict:
             "request_p95_s": float(np.percentile(np.asarray(latencies), 95)),
         },
         "required": {
-            "prefill": {"flops": flops.prefill_flops(sizes, B, Pn)},
+            "prefill": {"flops": family.prefill_flops(run.cell.config, B, Pn)},
             "decode_step": {"flops": dec_f, "bytes": dec_b},
         },
         "prompts": np.concatenate(prompts, axis=0),

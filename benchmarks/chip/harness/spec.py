@@ -9,7 +9,13 @@ found here by the name that ``BENCHMARK.json`` gives it:
 * ``cells/<workload>.json``   the limits that decide ``correct`` in that cell
 * ``programs/<role>.json``    jit-name patterns of one program in the trace
 * ``metrics/<metric>.py``     the reader of one per-layer metric
-* ``references/<name>.py``    a plain float32 reference, named by a config
+* ``references/<name>.py``    the family's module, named by a config's ``reference``
+
+A family's module is the only reader of its configuration file's own keys;
+the harness reads only ``name``, ``reference`` and ``program``.  Beside the
+plain float32 reference (``make_weights``, ``served_gaps``,
+``train_readings``) it gives what the sizes fix: ``program_fields``,
+``vocab``, ``train_step_flops``, ``prefill_flops`` and ``decode_step_work``.
 
 Nothing here imports JAX, so the files can be checked on any machine.
 """

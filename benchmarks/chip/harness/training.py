@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import flops
 from .spec import host_rng
 from .window import Run, leaf_paths
 
@@ -137,8 +136,7 @@ def run_cell(run: Run, break_step=None) -> Dict:
     del state, metrics, pending
     run.free_program()
 
-    sizes = flops.model_sizes(run.cell.config)
-    per_chip = flops.train_step_flops(sizes, B, S) / len(run.devices)
+    per_chip = run.reference().train_step_flops(run.cell.config, B, S) / len(run.devices)
     return {
         "attempted": n,
         "failed": 0,

@@ -51,21 +51,7 @@ class Run:
         c = self.cell.config
         prog = c["program"]
         cfg = configs.get_config(prog["arch"]).replace(**prog.get("overrides", {}))
-        d = int(c["d_model"])
-        want = {
-            "num_layers": int(c["n_layers"]),
-            "d_model": d,
-            "num_heads": int(c["n_heads"]),
-            "num_kv_heads": int(c.get("n_kv_heads") or c["n_heads"]),
-            "d_ff": int(c["mlp_ratio"]) * d // 2,
-            "vocab_size": int(c["embedding_size"]),
-            "tie_embeddings": bool(c["weight_tying"]),
-            "norm_kind": "nonparametric",
-            "mlp_kind": c["activation_type"],
-            "rope_theta": float(c["assumed"]["rope_theta"]),
-            "param_dtype": c["assumed"]["dtype"],
-            "compute_dtype": c["assumed"]["dtype"],
-        }
+        want = self.reference().program_fields(c)
         wrong = {k: (getattr(cfg, k), v) for k, v in want.items() if getattr(cfg, k) != v}
         if wrong:
             raise SpecError(f"the program's config differs from {c['name']}: {wrong}")
@@ -75,10 +61,12 @@ class Run:
         return device.weight_key(self.seed)
 
     def token_vocab(self) -> int:
-        """Token ids are drawn from the tokenizer's vocabulary, not the padding."""
-        return int(self.cell.config["vocab_size"])
+        """The range token ids are drawn from (the family's ``vocab``)."""
+        return self.reference().vocab(self.cell.config)[0]
 
     def reference(self):
+        """The family's module: the plain reference, and what the
+        configuration's sizes fix for the harness."""
         return reference_module(self.cell.config)
 
     # ---- the window ---------------------------------------------------------

@@ -30,6 +30,12 @@ configuration's bfloat16, and the benchmark has to judge it not correct.
 
 The LayerNorm eps is the configuration file's: the program's 1e-6, where
 OLMo's own code uses 1e-5 (``PERF.md`` lists it as an open question).
+
+This module is the only reader of OLMo's configuration keys.  Beside the
+reference it gives the harness what the published sizes fix: the program's
+fields (``program_fields``), the two ranges of token ids (``vocab``), and
+the work the cells require (``train_step_flops``, ``prefill_flops``,
+``decode_step_work``), counted by the conventions of ``harness/flops.py``.
 """
 from __future__ import annotations
 
@@ -41,6 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from harness.flops import causal_pairs
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -58,19 +66,97 @@ LAYER_LEAVES = (
 
 
 def sizes(config) -> Dict:
+    """The sizes of an OLMo configuration file, under the names used here."""
     d = int(config["d_model"])
     heads = int(config["n_heads"])
     return {
         "layers": int(config["n_layers"]),
         "d": d,
         "heads": heads,
+        "kv_heads": int(config["n_kv_heads"] or heads),
         "head_dim": d // heads,
-        "ffn": int(config["mlp_ratio"]) * d // 2,
+        "ffn": int(config["mlp_ratio"]) * d // 2,  # SwiGLU: the projection is split in two
+        "ids": int(config["vocab_size"]),
         "vocab": int(config["embedding_size"]),
+        "tied": bool(config["weight_tying"]),
+        "mlp": config["activation_type"],
         "theta": float(config["assumed"]["rope_theta"]),
         "eps": float(config["assumed"]["layer_norm_eps"]),
         "dtype": jnp.dtype(config["assumed"]["dtype"]),
     }
+
+
+# ---------------------------------------------------------------------------
+# what the harness takes from the configuration
+# ---------------------------------------------------------------------------
+
+def program_fields(config) -> Dict:
+    """The program's ``ModelConfig`` fields that the published sizes fix."""
+    s = sizes(config)
+    return {
+        "num_layers": s["layers"],
+        "d_model": s["d"],
+        "num_heads": s["heads"],
+        "num_kv_heads": s["kv_heads"],
+        "d_ff": s["ffn"],
+        "vocab_size": s["vocab"],
+        "tie_embeddings": s["tied"],
+        "norm_kind": "nonparametric",
+        "mlp_kind": s["mlp"],
+        "rope_theta": s["theta"],
+        "param_dtype": s["dtype"].name,
+        "compute_dtype": s["dtype"].name,
+    }
+
+
+def vocab(config):
+    """(ids, served): token ids are drawn below the tokenizer's vocabulary,
+    served ids must lie below the embedding's rows (the padded vocabulary)."""
+    s = sizes(config)
+    return s["ids"], s["vocab"]
+
+
+def layer_matmul_params(s) -> int:
+    """Weights one layer multiplies by: q, k, v, o and the three SwiGLU matrices."""
+    d, hd = s["d"], s["head_dim"]
+    attn = d * hd * (s["heads"] + 2 * s["kv_heads"]) + s["heads"] * hd * d
+    return attn + 3 * d * s["ffn"]
+
+
+def forward_flops(s, batch: int, seq: int, head_positions: int) -> float:
+    """One forward pass over ``batch`` sequences of ``seq`` tokens, with the
+    output head applied at ``head_positions`` positions of each sequence."""
+    tokens = batch * seq
+    dense = 2 * tokens * s["layers"] * layer_matmul_params(s)
+    # QK^T and PV: 2 * head_dim each per query-key pair and head
+    attn = s["layers"] * batch * 4 * s["heads"] * s["head_dim"] * causal_pairs(seq)
+    head = 2 * batch * head_positions * s["d"] * s["vocab"]
+    return float(dense + attn + head)
+
+
+def train_step_flops(config, batch: int, seq: int) -> float:
+    """Forward and backward of one step: three times the forward's matmuls
+    (the embedding lookup is a gather and counts nothing)."""
+    return 3.0 * forward_flops(sizes(config), batch, seq, head_positions=seq)
+
+
+def prefill_flops(config, batch: int, prompt: int) -> float:
+    return forward_flops(sizes(config), batch, prompt, head_positions=1)
+
+
+def decode_step_work(config, batch: int, context: int):
+    """(FLOPs, bytes) of one new token per sequence, attending to ``context``
+    keys (itself included): the weights read once (the tied embedding as the
+    output head) plus K and V at the ``context`` filled positions of every
+    layer."""
+    s = sizes(config)
+    dense = 2 * batch * s["layers"] * layer_matmul_params(s)
+    attn = s["layers"] * batch * 4 * s["heads"] * s["head_dim"] * context
+    head = 2 * batch * s["d"] * s["vocab"]
+    item = s["dtype"].itemsize  # weights and cache as the configuration stores them
+    weights = (s["layers"] * layer_matmul_params(s) + s["vocab"] * s["d"]) * item
+    cache = s["layers"] * 2 * batch * context * s["kv_heads"] * s["head_dim"] * item
+    return float(dense + attn + head), float(weights + cache)
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Run one cell's window under the profiler and say where its device time went,
 by the program's own scopes and host spans.
 
-  python3 benchmarks/chip/scope_report.py --workload olmo-1b.decode --seed 7 --seconds 30
+  python3 benchmarks/chip/scope_report.py --workload olmo-1b.decode --seed 7 --seconds 51
 
 The window is the one ``run.py --trace 1`` traces (same set-up, traffic and
 host loop); the run is not checked against the reference and reports no

@@ -40,6 +40,10 @@ TINY_PROGRAM = dict(
 )
 
 
+# what every family's module gives the harness (``harness/spec.py``)
+FAMILY_FUNCTIONS = ("program_fields", "vocab", "train_step_flops", "prefill_flops", "decode_step_work")
+
+
 def tiny_cell(workload: str, dtype: str = "bfloat16") -> spec.Cell:
     """The cell as BENCHMARK.json names it, at a size the CPU can hold."""
     cell = spec.load_cell(workload)
@@ -73,6 +77,9 @@ def test_every_file_is_found_by_name():
         assert (ROOT / c["file"]).is_file(), c["file"]
         cfg = spec.load_json(ROOT / c["file"])
         assert (BENCH / "references" / f"{cfg['reference']}.py").is_file()
+        family = spec.reference_module(cfg)
+        for fn in FAMILY_FUNCTIONS:
+            assert callable(getattr(family, fn, None)), (cfg["reference"], fn)
     for w in bench["workloads"]:
         cell = spec.load_cell(w["name"])
         assert cell.kind in ("training", "serving")
@@ -90,7 +97,10 @@ def test_every_file_is_found_by_name():
 def test_program_config_matches_configuration_files():
     for w in spec.load_benchmark()["workloads"]:
         cell = spec.load_cell(w["name"])
-        cfg = tiny_run(cell).program_config()
+        run = tiny_run(cell)
+        cfg = run.program_config()
+        fields = run.reference().program_fields(cell.config)
+        assert {k: getattr(cfg, k) for k in fields} == fields
         assert cfg.num_layers == cell.config["n_layers"]
 
 
@@ -215,12 +225,16 @@ def test_train_flops_match_the_compiled_dots():
     step, _, _ = make_train_step(api, cfg, OptConfig(), mesh, TrainHparams(), sds)
     state = jax.eval_shape(lambda: make_train_state(api, jax.random.PRNGKey(0)))
     counted = hloparse.analyze(step.lower(state, sds).compile().as_text()).flops
-    sizes = {
-        "layers": cfg.num_layers, "d_model": cfg.d_model, "heads": cfg.num_heads,
-        "kv_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim, "ffn": cfg.d_ff,
-        "vocab": cfg.vocab_size, "weight_bytes": 4, "cache_bytes": 4,
-    }
-    required = flops.train_step_flops(sizes, B, S)
+    # the OLMo configuration file of the smoke program's sizes
+    config = copy.deepcopy(spec.load_cell("olmo-1b-l8.train").config)
+    config.update(
+        d_model=cfg.d_model, n_heads=cfg.num_heads, n_kv_heads=cfg.num_kv_heads,
+        n_layers=cfg.num_layers, mlp_ratio=2 * cfg.d_ff // cfg.d_model,
+        vocab_size=cfg.vocab_size, embedding_size=cfg.vocab_size,
+    )
+    family = spec.reference_module(config)
+    assert family.sizes(config)["head_dim"] == cfg.head_dim
+    required = family.train_step_flops(config, B, S)
     # the program computes every query-key pair and masks half of them away;
     # the required count takes the causal triangle only
     masked = 3 * cfg.num_layers * B * 4 * cfg.num_heads * cfg.head_dim * (S * S - S * (S + 1) // 2)
@@ -228,11 +242,12 @@ def test_train_flops_match_the_compiled_dots():
 
 
 def test_required_counts_of_the_cells():
-    s = flops.model_sizes(spec.load_cell("olmo-1b-l8.train").config)
-    assert flops.layer_matmul_params(s) == 4 * 2048**2 + 3 * 2048 * 8192
-    assert flops.train_step_flops(s, 4, 1024) == pytest.approx(16.14e12, rel=1e-3)
-    s16 = flops.model_sizes(spec.load_cell("olmo-1b.decode").config)
-    f, b = flops.generate_decode_mean(s16, 32, 512, 128)
+    config = spec.load_cell("olmo-1b-l8.train").config
+    family = spec.reference_module(config)
+    assert family.layer_matmul_params(family.sizes(config)) == 4 * 2048**2 + 3 * 2048 * 8192
+    assert family.train_step_flops(config, 4, 1024) == pytest.approx(16.14e12, rel=1e-3)
+    c16 = spec.load_cell("olmo-1b.decode").config
+    f, b = flops.generate_decode_mean(lambda context: family.decode_step_work(c16, 32, context), 512, 128)
     # weights once (2.35 GB) plus K and V at 513..639 filled positions
     assert b == pytest.approx(2 * (16 * 67108864 + 50304 * 2048) + 16 * 2 * 32 * 576 * 2048 * 2, rel=1e-6)
     assert f > 0
